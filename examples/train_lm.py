@@ -10,8 +10,10 @@ step 120 exercises the restart path.
 Run:  PYTHONPATH=src python examples/train_lm.py [--steps 240]
 """
 import argparse
+import contextlib
 import dataclasses
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, "src")
@@ -21,6 +23,8 @@ import jax
 from repro import configs
 from repro.configs.base import RunConfig
 from repro.data import DataConfig, SyntheticLM
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.train import init_train_state, make_train_step
 from repro.models import Model
 from repro.optim import AdamW, AdamWConfig, cosine_schedule
@@ -32,7 +36,9 @@ from repro.configs.base import SHAPES
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=240)
-    p.add_argument("--ckpt-dir", default="/tmp/repro_example_ckpt")
+    p.add_argument("--ckpt-dir", default=None,
+                   help="checkpoint directory (default: a fresh temporary "
+                        "one, so every run starts from step 0)")
     args = p.parse_args()
 
     # deepseek-7b family at ~20M params
@@ -52,7 +58,8 @@ def main():
           f"({(plan.predicted_speedup-1)*100:.1f}% step-time win), "
           f"order={plan.order[:4]}...")
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    compile_cache.enable()
+    mesh = make_mesh((1, 1), ("data", "model"))
     run = RunConfig(sync_mode=plan.mode, remat=True, microbatches=1)
     model = Model(cfg, run, mesh=mesh)
     opt = AdamW(AdamWConfig(
@@ -69,15 +76,18 @@ def main():
             print(f"  step {step:4d}  loss {float(metrics['loss']):.4f}")
 
     t0 = time.monotonic()
-    summary = run_training(
-        LoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
-                   ckpt_every=60, fail_at_step=120),   # injected failure!
-        train_step=step_fn,
-        init_state=lambda: init_train_state(model, opt, run,
-                                            jax.random.PRNGKey(0)),
-        batch_at=data.batch_at,
-        monitor=monitor,
-        on_step=on_step)
+    with contextlib.ExitStack() as stack:
+        ckpt_dir = args.ckpt_dir or stack.enter_context(
+            tempfile.TemporaryDirectory(prefix="repro_example_ckpt_"))
+        summary = run_training(
+            LoopConfig(total_steps=args.steps, ckpt_dir=ckpt_dir,
+                       ckpt_every=60, fail_at_step=120),  # injected failure!
+            train_step=step_fn,
+            init_state=lambda: init_train_state(model, opt, run,
+                                                jax.random.PRNGKey(0)),
+            batch_at=data.batch_at,
+            monitor=monitor,
+            on_step=on_step)
     dt = time.monotonic() - t0
     first, last = summary["loss_history"][0], summary["loss_history"][-1]
     print(f"\ndone: {args.steps} steps in {dt:.0f}s, "

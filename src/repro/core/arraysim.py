@@ -1170,7 +1170,16 @@ class ResumableSim:
                     _defer((float(now + (sz - w) / r), 1, i, stamp[i]))
                 return
             if u < sz:
-                tgt = (math.floor(w / u + EPS) + 1) * u
+                du = math.floor(w / u + EPS)
+                if stream_out[i] and du != d_units[i]:
+                    # the work reached a unit boundary within EPS while
+                    # another event set this step, and i is rescheduled
+                    # before its own boundary event fired: fire it now,
+                    # or the next target skips the crossing and the
+                    # streaming consumers never see that unit
+                    _defer((now, 1, i, stamp[i]))
+                    return
+                tgt = (du + 1) * u
                 if tgt > sz:
                     tgt = sz
             else:

@@ -73,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, scale: float | None = None,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q: [B,H,S,hd]; k,v: [B,K,T,hd] with H % K == 0.  Returns [B,H,S,hd']."""
     B, H, S, hd = q.shape
     K, T = k.shape[1], k.shape[2]

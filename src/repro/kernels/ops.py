@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to "not on TPU": in this CPU container the kernel
-bodies execute in Python interpret mode for correctness validation; on a
-real TPU the same call sites compile to Mosaic.  ``flash_attention`` is
+On a TPU the kernels compile to Mosaic; on the CPU (the tests) their
+bodies run in Pallas interpret mode for correctness validation.  Any
+other backend has no kernel path and raises.  ``flash_attention`` is
 differentiable: the forward runs the kernel, the backward recomputes via
 the jnp oracle (standard recompute-flash; a fused bwd kernel is a listed
 follow-up in DESIGN.md).
@@ -22,7 +22,12 @@ from repro.kernels.ssd import ssd_intra_chunk as _ssd_intra
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for tpu and are interpreted on cpu; "
+            f"there is no path for the {backend!r} backend")
+    return backend == "cpu"
 
 
 # ----------------------------------------------------------------------

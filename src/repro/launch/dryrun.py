@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^^ MUST precede any jax import: jax locks the device count on first init.
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this:
@@ -21,6 +18,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Optional
@@ -32,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.configs.base import ArchConfig, RunConfig, SHAPES, \
     applicable_shapes
-from repro.launch import hlo_analysis, sharding as shard_lib
+from repro.launch import compile_cache, hlo_analysis, sharding as shard_lib
 from repro.launch.mesh import dp_axes, make_production_mesh, n_chips
 from repro.launch.specs import decode_specs, input_specs
 from repro.launch.train import (init_train_state, make_train_step,
@@ -210,6 +208,10 @@ def run_cells(archs, shapes, meshes, *, tag="baseline", force=False,
 
 
 def main(argv=None) -> None:
+    # the production meshes need 512 host devices; the CPU backend makes
+    # them only if this is set before its first use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    compile_cache.enable()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None)
     p.add_argument("--shape", default=None)

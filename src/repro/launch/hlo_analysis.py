@@ -8,8 +8,8 @@ sum is per-chip bytes on the wire; with the spec's convention
 (collective term = Σ_global / (chips × link_bw)) the chips cancel:
 term = per-chip bytes / link_bw.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants come from ``PEAKS``, one entry per ``device_kind``;
+the dry-run models a v5e pod, so its terms use the v5e entry.
 """
 from __future__ import annotations
 
@@ -17,10 +17,36 @@ import dataclasses
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-HBM_PER_CHIP = 16 * 1024 ** 3      # v5e: 16 GiB
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    ici_bw: float         # bytes/s per ICI link
+    hbm_bytes: int
+
+
+# Published per-chip peaks keyed by jax ``Device.device_kind``.
+# "TPU v5 lite" is TPU v5e — Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI per chip
+# over 4 links (50 GB/s each).
+V5E = "TPU v5 lite"
+PEAKS: dict[str, ChipPeaks] = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                   hbm_bytes=16 * 1024 ** 3),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
+_V5E_PEAKS = peaks(V5E)
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1,
@@ -84,15 +110,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / _V5E_PEAKS.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / _V5E_PEAKS.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / _V5E_PEAKS.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -115,7 +141,7 @@ class Roofline:
         """Useful-compute time / bound time — the score we hillclimb."""
         if self.bound_s <= 0:
             return 0.0
-        useful_s = self.model_flops / (self.chips * PEAK_FLOPS)
+        useful_s = self.model_flops / (self.chips * _V5E_PEAKS.flops)
         return useful_s / self.bound_s
 
     def to_dict(self) -> dict:
@@ -158,5 +184,5 @@ def memory_summary(compiled) -> dict:
     out["peak_estimate_bytes"] = (out["argument_size_in_bytes"]
                                   + out["temp_size_in_bytes"]
                                   - out.get("alias_size_in_bytes", 0))
-    out["fits_hbm"] = out["peak_estimate_bytes"] <= HBM_PER_CHIP
+    out["fits_hbm"] = out["peak_estimate_bytes"] <= _V5E_PEAKS.hbm_bytes
     return out

@@ -1,8 +1,9 @@
 """Serving step assembly: prefill + batched greedy decode.
 
 ``make_serve_step`` returns the single-token decode function the
-decode/long-context dry-run cells lower; ``main`` runs a small real
-serving demo (batched requests, continuous decode) on CPU.
+decode/long-context dry-run cells lower; ``main`` is a smoke demo: it
+serves the REDUCED config of ``--arch`` (batched requests, continuous
+decode) with random weights, on whatever backend jax finds.
 """
 from __future__ import annotations
 
@@ -14,7 +15,8 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.configs.base import RunConfig
-from repro.launch.mesh import dp_axes
+from repro.launch import compile_cache
+from repro.launch.mesh import dp_axes, make_mesh
 from repro.models import Model
 
 
@@ -34,7 +36,8 @@ def main(argv: Optional[list[str]] = None) -> None:
     p.add_argument("--gen", type=int, default=32)
     args = p.parse_args(argv)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    compile_cache.enable()
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = configs.get_smoke(args.arch)
     model = Model(cfg, RunConfig(remat=False), mesh=mesh,
                   dp_axes=dp_axes(mesh))

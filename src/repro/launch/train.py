@@ -7,24 +7,28 @@ state is {"params", "opt"[, "err"]}.  The gradient-sync *structure*
 ``RunConfig.sync_mode`` inside the model (see repro/sync/overlap.py).
 
 CLI:  PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m \
-          --steps 200 --batch 8 --seq 256
-runs a real (CPU-sized) training with checkpoint/restart support.
+          --steps 200 --batch 8 --seq 256 [--smoke] [--ckpt-dir DIR]
+runs a real training with checkpoint/restart support: the full config on
+a TPU, ``--smoke`` (the reduced config) on the CPU.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
+import tempfile
 import time
-from functools import partial
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.configs.base import ArchConfig, RunConfig, ShapeConfig
 from repro.data import DataConfig, SyntheticLM
-from repro.launch import sharding as shard_lib
-from repro.launch.mesh import dp_axes, make_production_mesh
+from repro.launch import compile_cache, sharding as shard_lib
+from repro.launch.mesh import dp_axes, make_mesh
 from repro.models import Model
 from repro.optim import AdamW, AdamWConfig, compression, cosine_schedule
 
@@ -61,7 +65,6 @@ def make_train_step(model: Model, optimizer: AdamW, run: RunConfig):
             # activations 4x across the rest (measured: per-layer
             # [B,S,d] all-gathers).  Pin: mb dim replicated, batch dim
             # sharded over dp.
-            from jax.sharding import NamedSharding, PartitionSpec as P
             dp = model.dp_axes
             mb = jax.tree.map(
                 lambda x: jax.lax.with_sharding_constraint(
@@ -122,8 +125,49 @@ def state_shardings(state_shapes: dict, cfg: ArchConfig, run: RunConfig,
     return out
 
 
+@dataclasses.dataclass
+class Trainer:
+    """A jitted train step with its state placed on a mesh; batches go
+    in under ``batch_shardings``."""
+    step: Callable               # (state, batch) -> (state, metrics)
+    init_state: Callable         # () -> state under ``state_shardings``
+    state_shardings: Any
+    state_shapes: Any
+    batch_shardings: dict
+    batch_shapes: dict
+
+
+def build_trainer(cfg: ArchConfig, run: RunConfig, mesh, *, batch: int,
+                  seq: int, steps: int, lr: float, seed: int = 0
+                  ) -> Trainer:
+    """Model and AdamW for ``cfg`` on ``mesh``.  The state is initialised
+    directly into its shardings and the step's inputs and outputs keep
+    them, so a multi-chip mesh never stages the state on one device."""
+    model = Model(cfg, run, mesh=mesh, dp_axes=dp_axes(mesh))
+    # warm up over the first tenth of the run (at most 20 steps), so a
+    # short run reaches its peak rate
+    opt = AdamW(AdamWConfig(
+        lr=cosine_schedule(lr, warmup=min(20, steps // 10), total=steps)))
+
+    def init():
+        return init_train_state(model, opt, run, jax.random.PRNGKey(seed))
+
+    state_shapes = jax.eval_shape(init)
+    st_sh = state_shardings(state_shapes, cfg, run, mesh)
+    batch_shapes = {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32)}
+    b_sh = shard_lib.batch_shardings(batch_shapes, mesh, run)
+    step = jax.jit(make_train_step(model, opt, run),
+                   in_shardings=(st_sh, b_sh),
+                   out_shardings=(st_sh, NamedSharding(mesh, P())),
+                   donate_argnums=0)
+    return Trainer(step=step, init_state=jax.jit(init, out_shardings=st_sh),
+                   state_shardings=st_sh,
+                   state_shapes=state_shapes, batch_shardings=b_sh,
+                   batch_shapes=batch_shapes)
+
+
 # ----------------------------------------------------------------------
-def main(argv: Optional[list[str]] = None) -> None:
+def main(argv: Optional[list[str]] = None) -> dict:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="mamba2-130m")
     p.add_argument("--smoke", action="store_true",
@@ -132,7 +176,9 @@ def main(argv: Optional[list[str]] = None) -> None:
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--seq", type=int, default=256)
     p.add_argument("--lr", type=float, default=3e-3)
-    p.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    p.add_argument("--ckpt-dir", default=None,
+                   help="resume from / checkpoint to this directory "
+                        "(default: a fresh temporary one)")
     p.add_argument("--ckpt-every", type=int, default=50)
     p.add_argument("--sync-mode", default="bucketed",
                    choices=["bucketed", "barrier"])
@@ -140,43 +186,43 @@ def main(argv: Optional[list[str]] = None) -> None:
                    help="dataxmodel, e.g. 2x1")
     args = p.parse_args(argv)
 
+    compile_cache.enable()
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get(args.arch)
     run = RunConfig(sync_mode=args.sync_mode, remat=True)
-    model = Model(cfg, run, mesh=mesh, dp_axes=dp_axes(mesh))
-    opt = AdamW(AdamWConfig(
-        lr=cosine_schedule(args.lr, warmup=20, total=args.steps)))
-
-    data = SyntheticLM(DataConfig(
-        vocab_size=cfg.vocab_size, seq_len=args.seq,
-        global_batch=args.batch))
+    trainer = build_trainer(cfg, run, mesh, batch=args.batch, seq=args.seq,
+                            steps=args.steps, lr=args.lr)
+    data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
+                                  global_batch=args.batch),
+                       sharding=trainer.batch_shardings["tokens"])
 
     from repro.runtime import LoopConfig, StepMonitor, run_training
-
-    step_fn = jax.jit(make_train_step(model, opt, run), donate_argnums=0)
-    monitor = StepMonitor()
 
     def on_step(step, metrics):
         if step % 10 == 0 or step == args.steps - 1:
             print(f"step {step:5d}  loss {float(metrics['loss']):.4f}")
 
     t0 = time.monotonic()
-    summary = run_training(
-        LoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
-                   ckpt_every=args.ckpt_every),
-        train_step=step_fn,
-        init_state=lambda: init_train_state(
-            model, opt, run, jax.random.PRNGKey(0)),
-        batch_at=data.batch_at,
-        monitor=monitor,
-        on_step=on_step)
+    with contextlib.ExitStack() as stack:
+        ckpt_dir = args.ckpt_dir or stack.enter_context(
+            tempfile.TemporaryDirectory(prefix="repro_ckpt_"))
+        summary = run_training(
+            LoopConfig(total_steps=args.steps, ckpt_dir=ckpt_dir,
+                       ckpt_every=args.ckpt_every),
+            train_step=trainer.step,
+            init_state=trainer.init_state,
+            batch_at=data.batch_at,
+            state_shardings=trainer.state_shardings,
+            monitor=StepMonitor(),
+            on_step=on_step)
     dt = time.monotonic() - t0
     print(f"done: {summary['final_step'] + 1} steps in {dt:.1f}s, "
           f"restarts={summary['restarts']}, "
           f"loss {summary['loss_history'][0]:.3f} -> "
           f"{summary['loss_history'][-1]:.3f}")
+    return summary
 
 
 if __name__ == "__main__":

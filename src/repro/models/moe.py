@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.models.layers import dense_init
 
@@ -169,7 +168,7 @@ def moe_apply(p: Params, x: jax.Array, cfg: ArchConfig, *,
         in_specs += [P(None, "model"), P(None, "model"), P("model", None)]
         args += [p["shared_in"], p["shared_gate"], p["shared_out"]]
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(xspec, P(dp) if dp else P(None)))(*args)
     return y, jnp.mean(aux)

@@ -170,9 +170,12 @@ def run_training(loop: LoopConfig, *,
                  state_shardings: Any = None,
                  monitor: Optional[StepMonitor] = None,
                  on_step: Optional[Callable] = None) -> dict:
-    """Crash-safe training loop.  Returns summary dict."""
+    """Crash-safe training loop.  Returns summary dict; ``step_times``
+    holds each executed step's wall time (batch placement included,
+    ended when the step's outputs are ready on the device)."""
     restarts = 0
     history: list[float] = []
+    step_times: list[float] = []
     injected = {"armed": loop.fail_at_step is not None}
 
     while True:
@@ -193,9 +196,10 @@ def run_training(loop: LoopConfig, *,
                 t0 = time.monotonic()
                 batch = batch_at(step)
                 state, metrics = train_step(state, batch)
-                jax.block_until_ready(jax.tree.leaves(state)[0])
+                jax.block_until_ready((state, metrics))
                 dt = time.monotonic() - t0
                 history.append(float(metrics.get("loss", float("nan"))))
+                step_times.append(dt)
                 if monitor is not None:
                     monitor.record(step, dt)
                 if on_step is not None:
@@ -212,7 +216,7 @@ def run_training(loop: LoopConfig, *,
                 pending.join()
             return {"completed": True, "restarts": restarts,
                     "final_step": loop.total_steps - 1,
-                    "loss_history": history}
+                    "loss_history": history, "step_times": step_times}
         except SimulatedFailure:
             restarts += 1
             if restarts > loop.max_restarts:

@@ -19,7 +19,7 @@ from typing import Optional
 from repro.configs.base import ArchConfig, RunConfig, ShapeConfig
 from repro.core import MXDAGScheduler, simulate
 from repro.core.builders import ddl
-from repro.launch.hlo_analysis import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.launch.hlo_analysis import V5E, peaks
 
 
 @dataclasses.dataclass
@@ -41,12 +41,13 @@ def _per_layer_times(cfg: ArchConfig, shape: ShapeConfig, chips: int,
     n_layer = cfg.param_counts()["active"] / max(cfg.n_layers, 1)
     tokens = shape.global_batch * shape.seq_len
     dp = max(chips // tp, 1)
-    fp = 2.0 * n_layer * tokens / (chips * PEAK_FLOPS)
+    chip = peaks(V5E)
+    fp = 2.0 * n_layer * tokens / (chips * chip.flops)
     bp = 2.0 * fp
     # grad RS + param AG: 2 × layer grad bytes (bf16) across dp over ICI
     layer_bytes = (cfg.param_counts()["total"] / max(cfg.n_layers, 1)) \
         * 2.0 / tp
-    sync = 2.0 * layer_bytes * (dp - 1) / dp / ICI_BW
+    sync = 2.0 * layer_bytes * (dp - 1) / dp / chip.ici_bw
     return fp, bp, sync
 
 

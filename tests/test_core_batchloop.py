@@ -152,7 +152,7 @@ if HAVE_HYPOTHESIS:
         @settings(max_examples=15, deadline=None)
         def test_random_layered_bitexact(self, seed, n):
             g = builders.random_layered(n, n_hosts=8, min_width=2,
-                                        max_width=10, seed=seed)
+                                        max_width=8, seed=seed)
             cl = Cluster.for_graph(g)
             assert_bitexact(Simulator(g, cl).run(batch=True),
                             Simulator(g, cl).run(batch=False))

@@ -1,5 +1,6 @@
 """Property-based tests (hypothesis) for the MXDAG calculus & simulator."""
 import math
+import re
 
 import pytest
 
@@ -204,8 +205,15 @@ class TestEventCalendarEquivalence:
         g, cluster, policy, prio, rel, coflows = case
         kw = dict(policy=policy, priorities=prio, releases=rel,
                   coflows=coflows)
+        try:
+            ref = Simulator(g, cluster, **kw)._reference_run()
+        except RuntimeError as e:
+            # e.g. a coflow whose members depend on each other can never
+            # start: the event-calendar core must report the same
+            with pytest.raises(RuntimeError, match=re.escape(str(e))):
+                Simulator(g, cluster, **kw).run()
+            return
         new = Simulator(g, cluster, **kw).run()
-        ref = Simulator(g, cluster, **kw)._reference_run()
         for n in g.tasks:
             assert new.start[n] == pytest.approx(ref.start[n],
                                                  abs=1e-6), n

@@ -129,6 +129,24 @@ class TestPipelining:
         r = simulate(g)
         assert r.makespan == pytest.approx(G.len_pipelined([a, f]))
 
+    def test_unit_boundary_landing_on_a_completion_is_not_lost(self):
+        # t2's first unit ends (within EPS) when t1 finishes; t2 is then
+        # rescheduled before its own unit event fires, and t3 must still
+        # start at that boundary rather than when t2 finishes
+        from repro.core.graph import MXDAG as G
+        from repro.core.simulator import Simulator
+        u = 7.67498057627927
+        ts = [compute("t0", 2 * u, "H0", unit=u),
+              flow("t1", 2.0, "H0", "H2", unit=1.0),
+              compute("t2", 2 * u, "H2", unit=u),
+              flow("t3", 2.0, "H2", "H4", unit=1.0)]
+        g = MXDAG()
+        g.chain(*ts, pipelined=True)
+        for batch in (True, False):
+            r = Simulator(g).run(batch=batch)
+            assert r.start["t3"] == pytest.approx(r.finish["t1"])
+            assert r.makespan == pytest.approx(G.len_pipelined(ts))
+
     def test_unpipelined_chain_matches_eq1(self):
         a = compute("a", 4.0, "A", unit=1.0)
         f = flow("f", 8.0, "A", "B", unit=2.0)

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.configs.base import RunConfig, ShapeConfig
 from repro.launch import hlo_analysis, sharding as shard_lib
-from repro.launch.mesh import dp_axes
+from repro.launch.mesh import dp_axes, make_mesh
 from repro.launch.specs import decode_specs, input_specs
 from repro.launch.train import (init_train_state, make_train_step,
                                 model_flops, state_shardings)
@@ -28,7 +28,7 @@ from repro.models import Model
 from repro.optim import AdamW, AdamWConfig
 
 out = {}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = ShapeConfig("tiny_train", 64, 8, "train")
 dshape = ShapeConfig("tiny_decode", 64, 8, "decode")
 

@@ -15,7 +15,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.launch.hlo_cost import analyze_text
 
 out = {}
@@ -43,11 +43,11 @@ out["matmul_flops"] = analyze_text(comp2.as_text()).flops
 out["matmul_flops_xla"] = float(xc["flops"])
 
 # 3) psum inside a scan: collective bytes scale by trips
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 def h(xs):
     def body(c, x):
-        y = shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
-                      in_specs=P("d"), out_specs=P())(x)
+        y = jax.shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
+                          in_specs=P("d"), out_specs=P())(x)
         return c + y.sum(), None
     return jax.lax.scan(body, 0.0, xs)[0]
 comp3 = jax.jit(h).lower(
@@ -81,6 +81,13 @@ def probe():
                              os.path.abspath(__file__))))
     assert res.returncode == 0, res.stderr[-2000:]
     return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.launch.hlo_analysis import V5E, peaks
+    assert peaks(V5E).flops == 197e12 and peaks(V5E).hbm_bw == 819e9
+    with pytest.raises(KeyError, match="TPU v9"):
+        peaks("TPU v9")
 
 
 def test_scan_flops_scaled_by_trip_count(probe):

@@ -46,8 +46,10 @@ class TestFlashAttention:
         q = rand(ks[0], (1, 2, 256, 32), jnp.float32)
         k = rand(ks[1], (1, 2, 256, 32), jnp.float32)
         v = rand(ks[2], (1, 2, 256, 32), jnp.float32)
-        a = flash_attention_bhsd(q, k, v, block_q=64, block_k=64)
-        b = flash_attention_bhsd(q, k, v, block_q=128, block_k=32)
+        a = flash_attention_bhsd(q, k, v, block_q=64, block_k=64,
+                                 interpret=True)
+        b = flash_attention_bhsd(q, k, v, block_q=128, block_k=32,
+                                 interpret=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
 
@@ -130,6 +132,19 @@ class TestSSD:
                                    rtol=1e-3, atol=1e-3)
         np.testing.assert_allclose(np.asarray(final), np.asarray(finalr),
                                    rtol=1e-3, atol=1e-3)
+
+
+class TestBackend:
+    @pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                                   ("tpu", False)])
+    def test_interpret_only_on_cpu(self, monkeypatch, backend, interpret):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops._interpret_default() is interpret
+
+    def test_other_backends_have_no_kernel_path(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret_default()
 
 
 class TestGMM:

@@ -14,6 +14,7 @@ import pytest
 
 from repro import configs
 from repro.configs.base import RunConfig
+from repro.launch.mesh import make_mesh
 from repro.models import Model, derive_segments
 
 ALL_ARCHS = sorted(configs.ARCHS)
@@ -21,7 +22,7 @@ ALL_ARCHS = sorted(configs.ARCHS)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_batch(cfg, rng, B=2, S=16):

@@ -11,6 +11,7 @@ import pytest
 from repro.checkpoint import all_steps, latest_step, restore, save, \
     save_async
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.optim import AdamW, AdamWConfig, compression, cosine_schedule
 
 pytestmark = [pytest.mark.slow, pytest.mark.jax]
@@ -62,7 +63,7 @@ class TestCheckpoint:
         """Mesh-shape independence: restore device_puts per a sharding."""
         t = self.tree()
         save(str(tmp_path), 0, t)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         from jax.sharding import NamedSharding, PartitionSpec as P
         sh = jax.tree.map(lambda x: NamedSharding(
             mesh, P(*([None] * x.ndim))), t)

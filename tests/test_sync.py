@@ -21,12 +21,13 @@ import pytest
 
 from repro import configs
 from repro.configs.base import RunConfig, SHAPES
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 class TestNumericalEquivalence:
@@ -64,6 +65,7 @@ import json, re, sys
 import jax, jax.numpy as jnp
 from repro import configs
 from repro.configs.base import RunConfig
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.launch import sharding as shard_lib
 from repro.launch.train import init_train_state, make_train_step, state_shardings
@@ -74,7 +76,7 @@ from repro.launch.hlo_cost import parse_module, COLLECTIVES
 cfg = configs.get_smoke("deepseek-7b")
 import dataclasses
 cfg = dataclasses.replace(cfg, n_layers=4, vocab_size=512)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 out = {}
 for mode in ("barrier", "bucketed"):
     run = RunConfig(sync_mode=mode, remat=True, attn_impl="xla")

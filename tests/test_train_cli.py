@@ -1,0 +1,53 @@
+"""The training CLI (``python -m repro.launch.train``) end to end on the
+CPU, and the persistent compilation cache every entry point enables."""
+import math
+import os
+
+import pytest
+
+jax = pytest.importorskip("jax")
+from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
+
+from repro.checkpoint import all_steps  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
+from repro.launch.train import main  # noqa: E402
+
+pytestmark = [pytest.mark.jax]
+
+
+@pytest.fixture
+def restore_cache_config():
+    """Entry points set the process-wide cache directory: put it back."""
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("sync_mode", ["bucketed", "barrier"])
+def test_train_cli_runs_the_steps_it_was_asked_for(
+        tmp_path, monkeypatch, restore_cache_config, sync_mode):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    ckpt = tmp_path / "ckpt"
+    summary = main(["--arch", "mamba2-130m", "--smoke", "--steps", "2",
+                    "--sync-mode", sync_mode, "--ckpt-dir", str(ckpt)])
+    assert summary["restarts"] == 0 and summary["final_step"] == 1
+    assert len(summary["loss_history"]) == 2
+    assert len(summary["step_times"]) == 2
+    assert all(math.isfinite(x) for x in summary["loss_history"])
+    assert all_steps(str(ckpt)) == [1]
+
+
+def test_compile_cache_uses_the_env_dir_when_set(
+        tmp_path, monkeypatch, restore_cache_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_compile_cache_falls_back_to_the_checkout(
+        monkeypatch, restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
