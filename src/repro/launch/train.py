@@ -14,6 +14,7 @@ a TPU, ``--smoke`` (the reduced config) on the CPU.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import dataclasses
 import tempfile
@@ -89,14 +90,15 @@ def make_train_step(model: Model, optimizer: AdamW, run: RunConfig):
         grads, metrics = compute_grads(state["params"], batch)
 
         new_state = dict(state)
-        if run.grad_compression:
-            g8, scales, new_err = compression.compress_tree(
-                grads, state["err"])
-            grads = compression.decompress_tree(g8, scales)
-            new_state["err"] = new_err
+        with jax.named_scope("optimizer"):
+            if run.grad_compression:
+                g8, scales, new_err = compression.compress_tree(
+                    grads, state["err"])
+                grads = compression.decompress_tree(g8, scales)
+                new_state["err"] = new_err
 
-        new_params, new_opt = optimizer.update(
-            grads, state["opt"], state["params"])
+            new_params, new_opt = optimizer.update(
+                grads, state["opt"], state["params"])
         new_state["params"] = new_params
         new_state["opt"] = new_opt
         return new_state, metrics
@@ -204,6 +206,7 @@ def main(argv: Optional[list[str]] = None) -> dict:
         if step % 10 == 0 or step == args.steps - 1:
             print(f"step {step:5d}  loss {float(metrics['loss']):.4f}")
 
+    monitor = StepMonitor()
     t0 = time.monotonic()
     with contextlib.ExitStack() as stack:
         ckpt_dir = args.ckpt_dir or stack.enter_context(
@@ -215,12 +218,16 @@ def main(argv: Optional[list[str]] = None) -> dict:
             init_state=trainer.init_state,
             batch_at=data.batch_at,
             state_shardings=trainer.state_shardings,
-            monitor=StepMonitor(),
+            monitor=monitor,
             on_step=on_step)
     dt = time.monotonic() - t0
+    kinds = collections.Counter(r.kind for r in monitor.reports)
+    by_kind = ", ".join(f"{k} {n}" for k, n in sorted(kinds.items()))
     print(f"done: {summary['final_step'] + 1} steps in {dt:.1f}s, "
           f"restarts={summary['restarts']}, "
-          f"loss {summary['loss_history'][0]:.3f} -> "
+          f"stragglers={len(monitor.reports)}"
+          + (f" ({by_kind})" if by_kind else "")
+          + f", loss {summary['loss_history'][0]:.3f} -> "
           f"{summary['loss_history'][-1]:.3f}")
     return summary
 

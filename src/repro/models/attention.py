@@ -62,6 +62,7 @@ def _xla_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return ob.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, v.shape[-1])
 
 
+@jax.named_scope("attention_core")
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
          causal: bool,
          q_positions: Optional[jax.Array] = None,

@@ -189,47 +189,52 @@ class Model:
         cfg, run = self.cfg, self.run
         aux = jnp.zeros((), jnp.float32)
         new_cache = {}
-        h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
-        if spec.mixer == "attn":
-            c = cache.get("attn") if cache else None
-            if cfg.attn_type == "mla":
-                out, nc = attn.mla_apply(bp["attn"], h, cfg,
-                                         positions=positions, cache=c,
-                                         cache_index=cache_index,
-                                         impl=run.attn_impl)
+        with jax.named_scope("attention" if spec.mixer == "attn"
+                             else "ssm"):
+            h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
+            if spec.mixer == "attn":
+                c = cache.get("attn") if cache else None
+                if cfg.attn_type == "mla":
+                    out, nc = attn.mla_apply(bp["attn"], h, cfg,
+                                             positions=positions, cache=c,
+                                             cache_index=cache_index,
+                                             impl=run.attn_impl)
+                else:
+                    out, nc = attn.gqa_apply(bp["attn"], h, cfg,
+                                             positions=positions, cache=c,
+                                             cache_index=cache_index,
+                                             causal=spec.causal,
+                                             impl=run.attn_impl)
+                if nc is not None:
+                    new_cache["attn"] = nc
             else:
-                out, nc = attn.gqa_apply(bp["attn"], h, cfg,
-                                         positions=positions, cache=c,
-                                         cache_index=cache_index,
-                                         causal=spec.causal,
-                                         impl=run.attn_impl)
-            if nc is not None:
-                new_cache["attn"] = nc
-        else:
-            c = cache.get("ssm") if cache else None
-            out, nc = ssm_mod.ssm_apply(bp["ssm"], h, cfg, cache=c,
-                                        chunk=run.ssm_chunk or None)
-            if nc is not None:
-                new_cache["ssm"] = nc
-        x = x + out
-
-        if spec.cross and enc_out is not None:
-            h = rmsnorm(bp["ln_x"], x, cfg.norm_eps)
-            out, _ = attn.gqa_apply(bp["xattn"], h, cfg, kv_src=enc_out,
-                                    causal=False, use_rope=False,
-                                    impl=run.attn_impl)
+                c = cache.get("ssm") if cache else None
+                out, nc = ssm_mod.ssm_apply(bp["ssm"], h, cfg, cache=c,
+                                            chunk=run.ssm_chunk or None)
+                if nc is not None:
+                    new_cache["ssm"] = nc
             x = x + out
 
+        if spec.cross and enc_out is not None:
+            with jax.named_scope("attention"):
+                h = rmsnorm(bp["ln_x"], x, cfg.norm_eps)
+                out, _ = attn.gqa_apply(bp["xattn"], h, cfg, kv_src=enc_out,
+                                        causal=False, use_rope=False,
+                                        impl=run.attn_impl)
+                x = x + out
+
         if spec.ffn == "dense":
-            h = rmsnorm(bp["ln2"], x, cfg.norm_eps)
-            x = x + mlp(bp["mlp"], h, cfg.mlp_type)
+            with jax.named_scope("mlp"):
+                h = rmsnorm(bp["ln2"], x, cfg.norm_eps)
+                x = x + mlp(bp["mlp"], h, cfg.mlp_type)
         elif spec.ffn == "moe":
-            h = rmsnorm(bp["ln2"], x, cfg.norm_eps)
-            y, a = moe_mod.moe_apply(bp["moe"], h, cfg, mesh=self.mesh,
-                                     dp_axes=self.dp_axes,
-                                     combine=run.moe_combine)
-            x = x + y
-            aux = aux + a
+            with jax.named_scope("moe"):
+                h = rmsnorm(bp["ln2"], x, cfg.norm_eps)
+                y, a = moe_mod.moe_apply(bp["moe"], h, cfg, mesh=self.mesh,
+                                         dp_axes=self.dp_axes,
+                                         combine=run.moe_combine)
+                x = x + y
+                aux = aux + a
         # §Perf iter 5: pin the block output while it is still bf16 so
         # the TP partial-sum all-reduce runs on the bf16 residual rather
         # than sinking past the next layer's fp32 norm upcast.
@@ -329,6 +334,7 @@ class Model:
         (x,), _ = jax.lax.scan(b, (x,), params["encoder"])
         return rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
+    @jax.named_scope("embed")
     def _embed_inputs(self, params, batch):
         """Token (+ modality prefix) embedding.  Returns (x, n_prefix)."""
         cfg = self.cfg
@@ -369,6 +375,7 @@ class Model:
     def _vocab_sharded(self) -> bool:
         return True     # padding guarantees divisibility
 
+    @jax.named_scope("head")
     def _head(self, params, x):
         cfg = self.cfg
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
@@ -406,8 +413,9 @@ class Model:
         logits = self._head(params, h[:, :-1])
         if self.run.logits_fp32:
             logits = logits.astype(jnp.float32)
-        ce = cross_entropy(logits, tokens[:, 1:],
-                           vocab_sharded=self._vocab_sharded())
+        with jax.named_scope("loss"):
+            ce = cross_entropy(logits, tokens[:, 1:],
+                               vocab_sharded=self._vocab_sharded())
         loss = ce + cfg.router_aux_weight * aux
         metrics = {"ce": ce, "aux": aux}
         if cfg.mtp:
@@ -421,9 +429,10 @@ class Model:
                                         BlockSpec("attn", "dense"), z,
                                         positions=positions[: z.shape[1]])
             mtp_logits = self._head(params, z[:, :-1])
-            mtp_ce = cross_entropy(mtp_logits.astype(jnp.float32),
-                                   tokens[:, 2:],
-                                   vocab_sharded=self._vocab_sharded())
+            with jax.named_scope("loss"):
+                mtp_ce = cross_entropy(mtp_logits.astype(jnp.float32),
+                                       tokens[:, 2:],
+                                       vocab_sharded=self._vocab_sharded())
             loss = loss + 0.3 * mtp_ce
             metrics["mtp_ce"] = mtp_ce
         metrics["loss"] = loss

@@ -58,6 +58,7 @@ def _segsum(x: jax.Array) -> jax.Array:
     return jnp.where(mask, out, -jnp.inf)
 
 
+@jax.named_scope("ssm_core")
 def ssd_chunked(xh: jax.Array, dt: jax.Array, A: jax.Array,
                 Bm: jax.Array, Cm: jax.Array, chunk: int,
                 init_state: Optional[jax.Array] = None,

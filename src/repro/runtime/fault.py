@@ -17,6 +17,7 @@ import time
 from typing import Any, Callable, Optional
 
 import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.core.graph import MXDAG
@@ -172,7 +173,15 @@ def run_training(loop: LoopConfig, *,
                  on_step: Optional[Callable] = None) -> dict:
     """Crash-safe training loop.  Returns summary dict; ``step_times``
     holds each executed step's wall time (batch placement included,
-    ended when the step's outputs are ready on the device)."""
+    ended when the step's outputs are ready on the device).
+
+    Each step runs under ``StepTraceAnnotation("train", step_num=step)``
+    and its phases under ``TraceAnnotation``s, in this order:
+    ``run_training.batch`` (``batch_at``), ``.step`` (the dispatch),
+    ``.wait`` (``block_until_ready``), ``.record`` (loss, step time,
+    monitor), ``.on_step`` (the callback) and, on saving steps,
+    ``.save``.  They land in a running ``jax.profiler`` trace on the
+    device's clock and cost one check each when none runs."""
     restarts = 0
     history: list[float] = []
     step_times: list[float] = []
@@ -193,25 +202,34 @@ def run_training(loop: LoopConfig, *,
                 if injected["armed"] and step == loop.fail_at_step:
                     injected["armed"] = False
                     raise SimulatedFailure(f"injected at step {step}")
-                t0 = time.monotonic()
-                batch = batch_at(step)
-                state, metrics = train_step(state, batch)
-                jax.block_until_ready((state, metrics))
-                dt = time.monotonic() - t0
-                history.append(float(metrics.get("loss", float("nan"))))
-                step_times.append(dt)
-                if monitor is not None:
-                    monitor.record(step, dt)
-                if on_step is not None:
-                    on_step(step, metrics)
-                if (step + 1) % loop.ckpt_every == 0 \
-                        or step == loop.total_steps - 1:
-                    if loop.ckpt_async:
-                        pending = ckpt_lib.save_async(
-                            loop.ckpt_dir, step, state, keep=loop.keep)
-                    else:
-                        ckpt_lib.save(loop.ckpt_dir, step, state,
-                                      keep=loop.keep)
+                with StepTraceAnnotation("train", step_num=step):
+                    t0 = time.monotonic()
+                    with TraceAnnotation("run_training.batch"):
+                        batch = batch_at(step)
+                    with TraceAnnotation("run_training.step"):
+                        state, metrics = train_step(state, batch)
+                    with TraceAnnotation("run_training.wait"):
+                        jax.block_until_ready((state, metrics))
+                    with TraceAnnotation("run_training.record"):
+                        dt = time.monotonic() - t0
+                        history.append(
+                            float(metrics.get("loss", float("nan"))))
+                        step_times.append(dt)
+                        if monitor is not None:
+                            monitor.record(step, dt)
+                    if on_step is not None:
+                        with TraceAnnotation("run_training.on_step"):
+                            on_step(step, metrics)
+                    if (step + 1) % loop.ckpt_every == 0 \
+                            or step == loop.total_steps - 1:
+                        with TraceAnnotation("run_training.save"):
+                            if loop.ckpt_async:
+                                pending = ckpt_lib.save_async(
+                                    loop.ckpt_dir, step, state,
+                                    keep=loop.keep)
+                            else:
+                                ckpt_lib.save(loop.ckpt_dir, step, state,
+                                              keep=loop.keep)
             if pending is not None:
                 pending.join()
             return {"completed": True, "restarts": restarts,

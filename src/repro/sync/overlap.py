@@ -103,7 +103,8 @@ def make_synced_scan(body: Callable, sync: Optional[Callable]):
             # (bf16) halves those wire bytes (standard mixed precision)
             dxin = dxin.astype(x_in.dtype)
             if sync is not None:
-                dp = sync(dp)
+                with jax.named_scope("grad_sync"):
+                    dp = sync(dp)
             return dxin, dp
 
         dx0, dps = jax.lax.scan(step, dxf, (params_stack, xs),

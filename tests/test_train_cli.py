@@ -1,13 +1,16 @@
 """The training CLI (``python -m repro.launch.train``) end to end on the
 CPU, and the persistent compilation cache every entry point enables."""
+import functools
 import math
 import os
+import re
 
 import pytest
 
 jax = pytest.importorskip("jax")
 from jax.experimental.compilation_cache import compilation_cache  # noqa: E402
 
+from repro import runtime  # noqa: E402
 from repro.checkpoint import all_steps  # noqa: E402
 from repro.launch import compile_cache  # noqa: E402
 from repro.launch.train import main  # noqa: E402
@@ -26,8 +29,12 @@ def restore_cache_config():
 
 @pytest.mark.parametrize("sync_mode", ["bucketed", "barrier"])
 def test_train_cli_runs_the_steps_it_was_asked_for(
-        tmp_path, monkeypatch, restore_cache_config, sync_mode):
+        tmp_path, monkeypatch, capsys, restore_cache_config, sync_mode):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    # a monitor that calls every step after the first a straggler, so
+    # the done line's report count is known
+    monkeypatch.setattr(runtime, "StepMonitor",
+                        functools.partial(runtime.StepMonitor, threshold=0.0))
     ckpt = tmp_path / "ckpt"
     summary = main(["--arch", "mamba2-130m", "--smoke", "--steps", "2",
                     "--sync-mode", sync_mode, "--ckpt-dir", str(ckpt)])
@@ -36,6 +43,10 @@ def test_train_cli_runs_the_steps_it_was_asked_for(
     assert len(summary["step_times"]) == 2
     assert all(math.isfinite(x) for x in summary["loss_history"])
     assert all_steps(str(ckpt)) == [1]
+    done = capsys.readouterr().out.splitlines()[-1]
+    assert re.fullmatch(r"done: 2 steps in \d+\.\ds, restarts=0, "
+                        r"stragglers=1 \(step-time 1\), "
+                        r"loss \d+\.\d{3} -> \d+\.\d{3}", done), done
 
 
 def test_compile_cache_uses_the_env_dir_when_set(
