@@ -232,7 +232,7 @@ class RunConfig:
     sync_mode: str = "barrier"    # barrier (baseline) | bucketed
                                   # (layer-wise overlap per the MXDAG plan)
     moe_combine: str = "psum"     # psum | psum_scatter
-    attn_impl: str = "xla_flash"  # xla_flash | xla | pallas
+    attn_impl: str = "auto"       # auto | xla_flash | xla | pallas
     ssm_chunk: int = 0            # override ArchConfig.ssm_chunk (0 = keep)
     seq_shard: bool = False       # shard activations' seq dim over "model"
                                   # (SP for attention-free archs)

@@ -3,20 +3,20 @@
 On a TPU the kernels compile to Mosaic; on the CPU (the tests) their
 bodies run in Pallas interpret mode for correctness validation.  Any
 other backend has no kernel path and raises.  ``flash_attention`` is
-differentiable: the forward runs the kernel, the backward recomputes via
-the jnp oracle (standard recompute-flash; a fused bwd kernel is a listed
-follow-up in DESIGN.md).
+differentiable through one ``custom_vjp``: the forward kernel saves its
+output and per-row log-sum-exp, and the backward runs the dq and dk/dv
+kernels on them (``ref.flash_attention_ref`` is the tests' oracle).
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref as _ref
-from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels import flash_attention as _fa
 from repro.kernels.moe_gmm import gmm as _gmm
 from repro.kernels.ssd import ssd_intra_chunk as _ssd_intra
 
@@ -33,22 +33,38 @@ def _interpret_default() -> bool:
 # ----------------------------------------------------------------------
 # flash attention: [B,S,H,hd] layout (model-side convention)
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q_bhsd, k_bhsd, v_bhsd, causal, scale):
-    return flash_attention_bhsd(q_bhsd, k_bhsd, v_bhsd, causal=causal,
-                                scale=scale, interpret=_interpret_default())
+def _merge(x):
+    return x.reshape(*x.shape[:2], -1)
 
 
-def _flash_fwd(q, k, v, causal, scale):
-    return _flash(q, k, v, causal, scale), (q, k, v)
+def _kernel_args(q, k, causal, scale, blocks):
+    bq, bk = blocks or (_fa.block_size(q.shape[1]),
+                        _fa.block_size(k.shape[1]))
+    return dict(heads=(q.shape[2], k.shape[2]), causal=causal, scale=scale,
+                block_q=bq, block_k=bk, interpret=_interpret_default())
 
 
-def _flash_bwd(causal, scale, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _ref.flash_attention_ref(
-            q_, k_, v_, causal=causal, scale=scale), q, k, v)
-    return vjp(g)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, scale, blocks):
+    return _flash_fwd(q, k, v, causal, scale, blocks)[0]
+
+
+def _flash_fwd(q, k, v, causal, scale, blocks):
+    o, lse = _fa.flash_fwd(_merge(q), _merge(k), _merge(v),
+                           **_kernel_args(q, k, causal, scale, blocks))
+    o = o.reshape(*q.shape[:3], v.shape[3])
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bwd(causal, scale, blocks, res, do):
+    q, k, v, o, lse = res
+    d = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    d = jnp.swapaxes(d, 1, 2)[:, :, None, :]              # [B,H,1,S]
+    args = [_merge(q), _merge(k), _merge(v), _merge(do), lse, d]
+    kw = _kernel_args(q, k, causal, scale, blocks)
+    dq = _fa.flash_dq(*args, **kw)
+    dk, dv = _fa.flash_dkv(*args, **kw)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -56,13 +72,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
-                    scale: Optional[float] = None) -> jax.Array:
-    """q: [B,S,H,hd]; k,v: [B,T,K,hd] → [B,S,H,hd]  (GQA-aware)."""
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    o = _flash(qt, kt, vt, causal, scale)
-    return jnp.swapaxes(o, 1, 2)
+                    scale: Optional[float] = None,
+                    blocks: Optional[tuple[int, int]] = None) -> jax.Array:
+    """q: [B,S,H,hd]; k: [B,T,K,hd]; v: [B,T,K,hdv] → [B,S,H,hdv]
+    (GQA-aware).  ``blocks = (block_q, block_k)`` overrides the sizes
+    ``flash_attention.block_size`` picks from S and T."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[3])
+    return _flash(q, k, v, causal, float(scale), blocks)
 
 
 # ----------------------------------------------------------------------

@@ -30,7 +30,7 @@ from repro.configs.base import ArchConfig, RunConfig, ShapeConfig
 from repro.data import DataConfig, SyntheticLM
 from repro.launch import compile_cache, sharding as shard_lib
 from repro.launch.mesh import dp_axes, make_mesh
-from repro.models import Model
+from repro.models import Model, attention
 from repro.optim import AdamW, AdamWConfig, compression, cosine_schedule
 
 
@@ -194,6 +194,7 @@ def main(argv: Optional[list[str]] = None) -> dict:
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get(args.arch)
     run = RunConfig(sync_mode=args.sync_mode, remat=True)
+    traced = attention.DISPATCH.copy()
     trainer = build_trainer(cfg, run, mesh, batch=args.batch, seq=args.seq,
                             steps=args.steps, lr=args.lr)
     data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
@@ -223,10 +224,13 @@ def main(argv: Optional[list[str]] = None) -> dict:
     dt = time.monotonic() - t0
     kinds = collections.Counter(r.kind for r in monitor.reports)
     by_kind = ", ".join(f"{k} {n}" for k, n in sorted(kinds.items()))
+    paths = attention.DISPATCH - traced
+    by_path = ", ".join(f"{k} {n}" for k, n in sorted(paths.items()))
     print(f"done: {summary['final_step'] + 1} steps in {dt:.1f}s, "
           f"restarts={summary['restarts']}, "
           f"stragglers={len(monitor.reports)}"
           + (f" ({by_kind})" if by_kind else "")
+          + (f", attention ({by_path})" if by_path else "")
           + f", loss {summary['loss_history'][0]:.3f} -> "
           f"{summary['loss_history'][-1]:.3f}")
     return summary

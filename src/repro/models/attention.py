@@ -1,13 +1,23 @@
 """Attention: GQA (grouped-query) and MLA (multi-head latent), train +
 decode (KV cache) + cross-attention.
 
-The core dot-product attention has two implementations selectable per run
-(`RunConfig.attn_impl`):
+The core dot-product attention (``sdpa``) runs one of three paths — the
+Pallas flash kernels, ``_xla_flash`` or an einsum — chosen per call from
+``RunConfig.attn_impl``:
 
-- ``"xla"``   — einsum formulation (memory-efficient GQA grouping, fp32
-  softmax).  Used for dry-run lowering: it produces TPU-representative HLO.
-- ``"pallas"`` — the flash-attention kernel in ``repro.kernels`` (TPU
-  BlockSpec tiling; validated in interpret mode on CPU).
+- ``"auto"`` (the default) resolves by what the call shows: on a TPU,
+  causal self-attention (``S == T``, no cache length, positions absent
+  or 1-D) with one head dim for q, k and v that the kernels take
+  (``flash_attention.fits``) runs the Pallas flash kernels; otherwise
+  ``"xla_flash"`` where its conditions hold; otherwise ``"xla"``.  MLA's
+  192/128 heads, 64-wide heads, decode and cross-attention keep XLA.
+- ``"pallas"`` forces the kernels (forward and backward in
+  ``repro.kernels``; interpreted on the CPU) for causal self-attention.
+- ``"xla_flash"`` forces ``_xla_flash``: a scan over query blocks.
+- ``"xla"`` forces the einsum (memory-efficient GQA grouping, fp32
+  softmax), the path of decode and cross-attention.
+
+``DISPATCH`` tallies, at trace time, which path each ``sdpa`` call took.
 
 MLA decode uses the *absorbed* formulation: attention runs in the
 compressed-KV latent space so the cache holds only kv_lora+rope dims per
@@ -15,6 +25,7 @@ token (DeepSeek-V3's memory win).
 """
 from __future__ import annotations
 
+import collections
 import math
 from typing import Optional
 
@@ -62,6 +73,30 @@ def _xla_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return ob.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, v.shape[-1])
 
 
+DISPATCH: collections.Counter = collections.Counter()
+
+
+def _path(impl, q, k, v, *, causal, q_positions, k_valid_len) -> str:
+    """Which implementation a call takes: "kernel", "xla_flash" or
+    "einsum" (module docstring)."""
+    S, T = q.shape[1], k.shape[1]
+    self_attn = S == T and k_valid_len is None
+    flat_pos = q_positions is None or q_positions.ndim == 1
+    if impl == "auto":
+        from repro.kernels import flash_attention as _fa
+        hd = q.shape[3]
+        if (jax.default_backend() == "tpu" and causal and self_attn
+                and flat_pos and k.shape[3] == v.shape[3] == hd
+                and _fa.fits(S, hd, q.dtype.itemsize)):
+            return "kernel"
+        impl = "xla_flash"
+    if impl == "pallas" and causal and self_attn:
+        return "kernel"
+    if impl == "xla_flash" and self_attn and flat_pos and S % 256 == 0:
+        return "xla_flash"
+    return "einsum"
+
+
 @jax.named_scope("attention_core")
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
          causal: bool,
@@ -79,13 +114,13 @@ def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
     G = H // K
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
 
-    if impl == "pallas" and causal and S == T and k_valid_len is None:
+    path = _path(impl, q, k, v, causal=causal, q_positions=q_positions,
+                 k_valid_len=k_valid_len)
+    DISPATCH[path] += 1
+    if path == "kernel":
         from repro.kernels import ops as _kops
         return _kops.flash_attention(q, k, v, causal=True, scale=scale)
-
-    if impl == "xla_flash" and S == T and k_valid_len is None \
-            and (q_positions is None or q_positions.ndim == 1) \
-            and S % 256 == 0:
+    if path == "xla_flash":
         return _xla_flash(q, k, v, causal=causal, scale=scale)
 
     qg = q.reshape(B, S, K, G, hd)

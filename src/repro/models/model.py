@@ -129,6 +129,11 @@ class Model:
         self.mesh = mesh
         self.dp_axes = dp_axes
         self.dtype = dtype
+        # GSPMD cannot partition a Mosaic kernel: over several devices
+        # "auto" keeps attention on the XLA paths.
+        self.attn_impl = run.attn_impl
+        if run.attn_impl == "auto" and mesh is not None and mesh.size > 1:
+            self.attn_impl = "xla_flash"
         self.segments = derive_segments(cfg)
         self.enc_segments: list[Segment] = []
         if cfg.encoder_layers:
@@ -198,13 +203,13 @@ class Model:
                     out, nc = attn.mla_apply(bp["attn"], h, cfg,
                                              positions=positions, cache=c,
                                              cache_index=cache_index,
-                                             impl=run.attn_impl)
+                                             impl=self.attn_impl)
                 else:
                     out, nc = attn.gqa_apply(bp["attn"], h, cfg,
                                              positions=positions, cache=c,
                                              cache_index=cache_index,
                                              causal=spec.causal,
-                                             impl=run.attn_impl)
+                                             impl=self.attn_impl)
                 if nc is not None:
                     new_cache["attn"] = nc
             else:
@@ -220,7 +225,7 @@ class Model:
                 h = rmsnorm(bp["ln_x"], x, cfg.norm_eps)
                 out, _ = attn.gqa_apply(bp["xattn"], h, cfg, kv_src=enc_out,
                                         causal=False, use_rope=False,
-                                        impl=run.attn_impl)
+                                        impl=self.attn_impl)
                 x = x + out
 
         if spec.ffn == "dense":

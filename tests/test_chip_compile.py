@@ -1,5 +1,6 @@
-"""Compiles for a described TPU v5e: the three Pallas kernels at real
-widths and the full-width mamba2-130m train step (2 layers).
+"""Compiles for a described TPU v5e: the Pallas kernels at real widths
+(flash attention's forward, dq and dk/dv at deepseek-7b-l2.b8s4k's shape,
+and its gradient), and the full-width mamba2-130m train step (2 layers).
 
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached.  Nothing runs, so these tests say nothing about
@@ -18,7 +19,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from repro import configs  # noqa: E402
 from repro.configs.base import RunConfig  # noqa: E402
-from repro.kernels.flash_attention import flash_attention_bhsd  # noqa: E402
+from repro.kernels import flash_attention as fa, ops  # noqa: E402
 from repro.kernels.moe_gmm import gmm  # noqa: E402
 from repro.kernels.ssd import ssd_intra_chunk  # noqa: E402
 from repro.launch import hlo_analysis  # noqa: E402
@@ -58,11 +59,45 @@ def test_described_chip_is_a_v5e(topo):
     assert hlo_analysis.peaks(topo.devices[0].device_kind).flops == 197e12
 
 
+# deepseek-7b-l2.b8s4k: batch 8, 32 heads of 128, sequence 4096, bf16
+B, S, H, HD = 8, 4096, 32, 128
+_FLASH = dict(heads=(H, H), causal=True, scale=HD ** -0.5,
+              block_q=fa.block_size(S), block_k=fa.block_size(S),
+              interpret=False)
+_QKV = ((B, S, H * HD), jnp.bfloat16)
+_ROW = ((B, H, 1, S), jnp.float32)
+
+
 def test_flash_attention_compiles(one_chip):
-    bf = jnp.bfloat16
-    q = ((1, 32, 4096, 128), bf)
-    _compile_kernel(lambda q, k, v: flash_attention_bhsd(
-        q, k, v, causal=True, interpret=False), one_chip, q, q, q)
+    _compile_kernel(lambda q, k, v: fa.flash_fwd(q, k, v, **_FLASH),
+                    one_chip, _QKV, _QKV, _QKV)
+
+
+@pytest.mark.parametrize("kernel", ["flash_dq", "flash_dkv"])
+def test_flash_attention_backward_kernels_compile(one_chip, kernel):
+    fn = getattr(fa, kernel)
+    _compile_kernel(lambda *a: fn(*a, **_FLASH), one_chip,
+                    _QKV, _QKV, _QKV, _QKV, _ROW, _ROW)
+
+
+def test_flash_attention_gradient_compiles_in_its_scope(one_chip,
+                                                        monkeypatch):
+    """jax.grad through the custom_vjp: forward, dq and dk/dv kernels,
+    each keeping the caller's ``attention_core`` scope in its op_name."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(q, k, v):
+        with jax.named_scope("attention_core"):
+            o = ops.flash_attention(q, k, v)
+        return jnp.sum(o.astype(jnp.float32))
+
+    shape = ((B, S, H, HD), jnp.bfloat16)
+    compiled = _compile_kernel(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                               shape, shape, shape)
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert all("attention_core" in ln for ln in calls), calls
 
 
 def test_gmm_compiles(one_chip):

@@ -41,20 +41,17 @@ class TestFlashAttention:
 
     def test_block_size_invariance(self):
         """Result must not depend on the tiling."""
-        from repro.kernels.flash_attention import flash_attention_bhsd
         ks = jax.random.split(jax.random.PRNGKey(1), 3)
-        q = rand(ks[0], (1, 2, 256, 32), jnp.float32)
-        k = rand(ks[1], (1, 2, 256, 32), jnp.float32)
-        v = rand(ks[2], (1, 2, 256, 32), jnp.float32)
-        a = flash_attention_bhsd(q, k, v, block_q=64, block_k=64,
-                                 interpret=True)
-        b = flash_attention_bhsd(q, k, v, block_q=128, block_k=32,
-                                 interpret=True)
+        q = rand(ks[0], (1, 256, 2, 32), jnp.float32)
+        k = rand(ks[1], (1, 256, 2, 32), jnp.float32)
+        v = rand(ks[2], (1, 256, 2, 32), jnp.float32)
+        a = ops.flash_attention(q, k, v, blocks=(64, 64))
+        b = ops.flash_attention(q, k, v, blocks=(128, 32))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
 
     def test_gradient_flows(self):
-        """custom_vjp: kernel fwd + recompute bwd."""
+        """custom_vjp: kernel fwd + dq and dk/dv kernels."""
         ks = jax.random.split(jax.random.PRNGKey(2), 3)
         q = rand(ks[0], (1, 128, 2, 32), jnp.float32)
         k = rand(ks[1], (1, 128, 2, 32), jnp.float32)
@@ -74,6 +71,88 @@ class TestFlashAttention:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-4)
+
+
+def _bhsd(x):
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _ref_bshd(q, k, v, causal=True):
+    """The oracle in f32 on [B,S,H,hd] inputs."""
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    return _bhsd(ref.flash_attention_ref(*map(_bhsd, f32), causal=causal))
+
+
+def _lse_ref(q, k, causal=True):
+    """Per-row log-sum-exp of the oracle's scaled, masked f32 scores."""
+    B, S, H, hd = q.shape
+    K = k.shape[2]
+    kr = jnp.repeat(k.astype(jnp.float32), H // K, axis=2)
+    s = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32), kr,
+                   precision="highest") / np.sqrt(hd)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+    return jax.nn.logsumexp(s, axis=-1)                   # [B,H,S]
+
+
+# heads (H, K), sequence and (block_q, block_k): S spans several blocks,
+# and unequal blocks leave diagonal blocks partly masked
+KERNEL_CASES = [
+    pytest.param((4, 4), 256, (64, 64), id="mha-64x64"),
+    pytest.param((8, 2), 384, (128, 64), id="gqa4-128x64"),
+    pytest.param((4, 1), 256, (64, 128), id="mqa-64x128"),
+    pytest.param((4, 4), 512, (128, 256), id="mha-128x256"),
+]
+KERNEL_TOL = {jnp.float32: dict(rtol=1e-4, atol=1e-4),
+              jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
+
+
+class TestFlashKernels:
+    """The forward, dq and dk/dv kernels against the oracle."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("heads,S,blocks", KERNEL_CASES)
+    def test_forward_and_lse_match_ref(self, heads, S, blocks, dtype):
+        from repro.kernels.flash_attention import flash_fwd
+        (H, K), hd = heads, 32
+        ks = jax.random.split(jax.random.PRNGKey(8), 3)
+        q = rand(ks[0], (2, S, H, hd), dtype)
+        k = rand(ks[1], (2, S, K, hd), dtype)
+        v = rand(ks[2], (2, S, K, hd), dtype)
+        o, lse = flash_fwd(
+            q.reshape(2, S, H * hd), k.reshape(2, S, K * hd),
+            v.reshape(2, S, K * hd), heads=heads, causal=True,
+            scale=hd ** -0.5, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)
+        assert o.dtype == dtype and lse.shape == (2, H, 1, S)
+        np.testing.assert_allclose(
+            np.asarray(o.reshape(2, S, H, hd), np.float32),
+            np.asarray(_ref_bshd(q, k, v)), **KERNEL_TOL[dtype])
+        np.testing.assert_allclose(np.asarray(lse[:, :, 0]),
+                                   np.asarray(_lse_ref(q, k)),
+                                   rtol=1e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("heads,S,blocks", KERNEL_CASES)
+    def test_gradients_match_ref(self, heads, S, blocks, dtype):
+        """dq, dk, dv through ``ops.flash_attention`` against ``jax.vjp``
+        of the oracle, at the scale of each gradient."""
+        (H, K), hd = heads, 32
+        ks = jax.random.split(jax.random.PRNGKey(9), 4)
+        q = rand(ks[0], (1, S, H, hd), dtype)
+        k = rand(ks[1], (1, S, K, hd), dtype)
+        v = rand(ks[2], (1, S, K, hd), dtype)
+        g = rand(ks[3], (1, S, H, hd), dtype)
+        _, vjp = jax.vjp(lambda *a: ops.flash_attention(*a, blocks=blocks),
+                         q, k, v)
+        _, vjp_ref = jax.vjp(_ref_bshd, q, k, v)
+        for got, want in zip(vjp(g), vjp_ref(g.astype(jnp.float32))):
+            assert got.dtype == dtype
+            got, want = np.asarray(got, np.float32), np.asarray(want)
+            scale = np.abs(want).max()
+            tol = KERNEL_TOL[dtype]
+            np.testing.assert_allclose(got / scale, want / scale,
+                                       rtol=tol["rtol"], atol=tol["atol"])
 
 
 class TestSSD:

@@ -62,3 +62,14 @@ def test_compile_cache_falls_back_to_the_checkout(
     path = compile_cache.enable()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert path == os.path.join(root, ".jax_cache")
+
+
+def test_done_line_counts_the_attention_paths(
+        tmp_path, monkeypatch, capsys, restore_cache_config):
+    """The tally of ``sdpa`` dispatches, beside the monitor's counts: on
+    the CPU a llama's causal self-attention takes the blockwise path."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    main(["--arch", "deepseek-7b", "--smoke", "--steps", "1", "--seq", "256",
+          "--batch", "2", "--ckpt-dir", str(tmp_path / "ckpt")])
+    done = capsys.readouterr().out.splitlines()[-1]
+    assert re.search(r", attention \(xla_flash \d+\), loss ", done), done
