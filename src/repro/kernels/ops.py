@@ -6,6 +6,8 @@ other backend has no kernel path and raises.  ``flash_attention`` is
 differentiable through one ``custom_vjp``: the forward kernel saves its
 output and per-row log-sum-exp, and the backward runs the dq and dk/dv
 kernels on them (``ref.flash_attention_ref`` is the tests' oracle).
+q, k, v, the output and the log-sum-exp carry ``checkpoint_name``s
+(``qkv``, ``core_out``, ``core_lse``) for the layer scan's remat.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels.moe_gmm import gmm as _gmm
@@ -50,9 +53,14 @@ def _flash(q, k, v, causal, scale, blocks):
 
 
 def _flash_fwd(q, k, v, causal, scale, blocks):
+    # the residuals carry ``checkpoint_name``s the layer scan's remat may
+    # keep (``model.REMAT_TIERS``); o is named as the primal output too,
+    # or a remat that keeps its residual copy still recomputes it
+    q, k, v = (checkpoint_name(t, "qkv") for t in (q, k, v))
     o, lse = _fa.flash_fwd(_merge(q), _merge(k), _merge(v),
                            **_kernel_args(q, k, causal, scale, blocks))
-    o = o.reshape(*q.shape[:3], v.shape[3])
+    o = checkpoint_name(o.reshape(*q.shape[:3], v.shape[3]), "core_out")
+    lse = checkpoint_name(lse, "core_lse")
     return o, (q, k, v, o, lse)
 
 

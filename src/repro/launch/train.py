@@ -17,6 +17,7 @@ import argparse
 import collections
 import contextlib
 import dataclasses
+import math
 import tempfile
 import time
 from typing import Any, Callable, Optional
@@ -31,6 +32,7 @@ from repro.data import DataConfig, SyntheticLM
 from repro.launch import compile_cache, sharding as shard_lib
 from repro.launch.mesh import dp_axes, make_mesh
 from repro.models import Model, attention
+from repro.models import model as model_lib
 from repro.optim import AdamW, AdamWConfig, compression, cosine_schedule
 
 
@@ -156,6 +158,23 @@ def build_trainer(cfg: ArchConfig, run: RunConfig, mesh, *, batch: int,
 
     state_shapes = jax.eval_shape(init)
     st_sh = state_shardings(state_shapes, cfg, run, mesh)
+    # kept residuals raise the step's peak byte for byte (the backward
+    # loop holds every layer's beside a copy of the current layer's), and
+    # the step's other temporaries take about half of what the state
+    # leaves (PERF.md §6): the layer scan keeps what fits in a third of
+    # it, and a sixth stays spare
+    stats = jax.local_devices()[0].memory_stats()
+    if stats is not None:
+        state_bytes = sum(
+            math.prod(sh.shard_shape(x.shape)) * x.dtype.itemsize
+            for x, sh in zip(jax.tree.leaves(state_shapes),
+                             jax.tree.leaves(st_sh)))
+        local = max(1, batch // (model.dp_size() * run.microbatches))
+        need = collections.Counter()
+        for seg in model.segments:
+            need.update(model.tier_bytes(seg, local, seq))
+        model.saved_tiers = model_lib.remat_saves(
+            need, (stats["bytes_limit"] - state_bytes) / 3)
     batch_shapes = {"tokens": jax.ShapeDtypeStruct((batch, seq), jnp.int32)}
     b_sh = shard_lib.batch_shardings(batch_shapes, mesh, run)
     step = jax.jit(make_train_step(model, opt, run),
@@ -195,6 +214,7 @@ def main(argv: Optional[list[str]] = None) -> dict:
         else configs.get(args.arch)
     run = RunConfig(sync_mode=args.sync_mode, remat=True)
     traced = attention.DISPATCH.copy()
+    remat_kept = model_lib.REMAT.copy()
     trainer = build_trainer(cfg, run, mesh, batch=args.batch, seq=args.seq,
                             steps=args.steps, lr=args.lr)
     data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
@@ -226,11 +246,15 @@ def main(argv: Optional[list[str]] = None) -> dict:
     by_kind = ", ".join(f"{k} {n}" for k, n in sorted(kinds.items()))
     paths = attention.DISPATCH - traced
     by_path = ", ".join(f"{k} {n}" for k, n in sorted(paths.items()))
+    kept = model_lib.REMAT - remat_kept
+    by_tiers = ", ".join(f"{k} {n / 1e9:.2f} GB"
+                         for k, n in sorted(kept.items()))
     print(f"done: {summary['final_step'] + 1} steps in {dt:.1f}s, "
           f"restarts={summary['restarts']}, "
           f"stragglers={len(monitor.reports)}"
           + (f" ({by_kind})" if by_kind else "")
           + (f", attention ({by_path})" if by_path else "")
+          + (f", remat: {by_tiers}" if by_tiers else "")
           + f", loss {summary['loss_history'][0]:.3f} -> "
           f"{summary['loss_history'][-1]:.3f}")
     return summary

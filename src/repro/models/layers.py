@@ -12,6 +12,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 Params = dict
 
@@ -139,12 +140,15 @@ def mlp_init(key, d: int, ff: int, kind: str, dtype=jnp.bfloat16) -> Params:
 
 
 def mlp(p: Params, x: jax.Array, kind: str) -> jax.Array:
+    """The input products carry the ``checkpoint_name``s ``mlp_gate`` and
+    ``mlp_up``, which the layer scan's remat may keep (``model.py``)."""
     if kind == "swiglu":
-        h = jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_in"])
+        h = jax.nn.silu(checkpoint_name(x @ p["w_gate"], "mlp_gate")) \
+            * checkpoint_name(x @ p["w_in"], "mlp_up")
     elif kind == "relu2":
-        h = jnp.square(jax.nn.relu(x @ p["w_in"]))
+        h = jnp.square(jax.nn.relu(checkpoint_name(x @ p["w_in"], "mlp_up")))
     elif kind == "gelu":
-        h = jax.nn.gelu(x @ p["w_in"])
+        h = jax.nn.gelu(checkpoint_name(x @ p["w_in"], "mlp_up"))
     else:
         raise ValueError(kind)
     return h @ p["w_out"]

@@ -13,9 +13,15 @@ The Model exposes:
 - ``forward(params, batch)``        → logits (prefill)
 - ``init_cache(batch, max_len)``    → decode cache pytree
 - ``decode_step(params, cache, tokens, index)`` → (logits, cache)
+
+With ``RunConfig.remat`` the scan body is rematerialised in the backward
+pass.  ``Model.saved_tiers`` (set by ``launch/train.py:build_trainer``
+from ``remat_saves``) names the residuals it keeps instead of
+recomputing them.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from functools import partial
@@ -94,6 +100,42 @@ def derive_segments(cfg: ArchConfig, *, cross: bool = False,
 
 
 # ----------------------------------------------------------------------
+# what the layer scan's remat keeps
+# ----------------------------------------------------------------------
+# Tiers of residuals, by their ``checkpoint_name``s (``layers.mlp``,
+# ``kernels/ops.py:_flash_fwd``), in the order ``remat_saves`` takes
+# them: most recompute removed per byte kept first (PERF.md §5).
+REMAT_TIERS = {"core": ("core_out", "core_lse"),
+               "mlp": ("mlp_gate", "mlp_up"),
+               "qkv": ("qkv",)}
+
+# Bytes one device keeps across the remat, by the tiers kept ("core,mlp"),
+# tallied per scanned segment at trace time.
+REMAT: collections.Counter = collections.Counter()
+
+
+def remat_saves(tier_bytes: dict[str, int],
+                budget: Optional[float]) -> tuple[str, ...]:
+    """The tiers the layer scan keeps: taken in ``REMAT_TIERS``' order
+    while the bytes they keep over all scanned layers (``tier_bytes``,
+    one device's) fit in ``budget``, up to the first that does not.  A
+    tier of no bytes (the model never names it) is passed over; without
+    a budget (a device that reports no memory) nothing is kept."""
+    if budget is None:
+        return ()
+    kept, total = [], 0
+    for tier in REMAT_TIERS:
+        b = tier_bytes.get(tier, 0)
+        if not b:
+            continue
+        if total + b > budget:
+            break
+        kept.append(tier)
+        total += b
+    return tuple(kept)
+
+
+# ----------------------------------------------------------------------
 # per-block init / apply
 # ----------------------------------------------------------------------
 def _block_init(key, spec: BlockSpec, cfg: ArchConfig, dtype) -> Params:
@@ -134,6 +176,8 @@ class Model:
         self.attn_impl = run.attn_impl
         if run.attn_impl == "auto" and mesh is not None and mesh.size > 1:
             self.attn_impl = "xla_flash"
+        # the REMAT_TIERS the layer scan's remat keeps (remat_saves)
+        self.saved_tiers: tuple[str, ...] = ()
         self.segments = derive_segments(cfg)
         self.enc_segments: list[Segment] = []
         if cfg.encoder_layers:
@@ -186,6 +230,41 @@ class Model:
                 "ln": rmsnorm_init(cfg.d_model, dtype),
             }
         return p
+
+    def tier_bytes(self, seg: Segment, batch: int, seq: int
+                   ) -> dict[str, int]:
+        """Bytes each of ``REMAT_TIERS`` keeps over ``seg``'s scan for a
+        ``[batch, seq]`` input on one device.  The core and qkv residuals
+        exist only where attention takes the flash kernels."""
+        cfg = self.cfg
+        item = jnp.dtype(self.dtype).itemsize
+        H = cfg.n_heads
+        if cfg.attn_type == "mla":
+            K, hd = H, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            hdv = cfg.v_head_dim
+        else:
+            K, hd, hdv = cfg.n_kv_heads, cfg.head_dim, cfg.head_dim
+        q = jax.ShapeDtypeStruct((batch, seq, H, hd), self.dtype)
+        k = jax.ShapeDtypeStruct((batch, seq, K, hd), self.dtype)
+        v = jax.ShapeDtypeStruct((batch, seq, K, hdv), self.dtype)
+        tokens = batch * seq
+        out = dict.fromkeys(REMAT_TIERS, 0)
+        for spec in seg.pattern:
+            if spec.mixer == "attn" and attn._path(
+                    self.attn_impl, q, k, v, causal=spec.causal,
+                    q_positions=None, k_valid_len=None) == "kernel":
+                out["core"] += tokens * H * (hdv * item + 4)  # o, f32 lse
+                out["qkv"] += tokens * (H * hd + K * (hd + hdv)) * item
+            if spec.ffn == "dense":
+                n = 2 if cfg.mlp_type == "swiglu" else 1
+                out["mlp"] += tokens * cfg.d_ff * n * item
+        return {t: b * seg.repeats for t, b in out.items()}
+
+    def dp_size(self) -> int:
+        """Devices the batch is split over."""
+        if self.mesh is None:
+            return 1
+        return math.prod(self.mesh.shape[a] for a in self.dp_axes)
 
     # ------------------------------------------------------------------
     def _apply_block(self, bp: Params, spec: BlockSpec, x, *,
@@ -245,11 +324,7 @@ class Model:
         # than sinking past the next layer's fp32 norm upcast.
         if self.mesh is not None and cache is None and x.ndim == 3:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            B = x.shape[0]
-            dpsz = 1
-            for a_ in self.dp_axes:
-                dpsz *= self.mesh.shape[a_]
-            if B % max(dpsz, 1) == 0:
+            if x.shape[0] % self.dp_size() == 0:
                 x = jax.lax.with_sharding_constraint(
                     x, NamedSharding(self.mesh,
                                      P(self.dp_axes, None, None)))
@@ -314,7 +389,8 @@ class Model:
                 return (xc, auxc), ncs
 
             if self.run.remat:
-                body = jax.checkpoint(body)
+                body = self._remat(body, seg, x) if caches is None \
+                    else jax.checkpoint(body)
             (x, total_aux), nc_stack = jax.lax.scan(
                 body, (x, total_aux),
                 (params_stack,
@@ -322,6 +398,20 @@ class Model:
                  else [None] * len(seg.pattern)))
             new_caches.append(nc_stack)
         return x, total_aux, new_caches
+
+    def _remat(self, body, seg: Segment, x):
+        """``body`` under ``jax.checkpoint``, keeping the residuals of the
+        ``saved_tiers`` that ``seg`` has (tallied in ``REMAT``)."""
+        have = self.tier_bytes(seg, x.shape[0] // self.dp_size(),
+                               x.shape[1])
+        kept = [t for t in self.saved_tiers if have[t]]
+        if not kept:
+            return jax.checkpoint(body)
+        REMAT[",".join(kept)] += sum(have[t] for t in kept)
+        names = [n for t in kept for n in REMAT_TIERS[t]]
+        return jax.checkpoint(
+            body, policy=jax.checkpoint_policies.save_only_these_names(
+                *names))
 
     # ------------------------------------------------------------------
     def _encode(self, params, batch):

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e: the Pallas kernels at real widths
 (flash attention's forward, dq and dk/dv at deepseek-7b-l2.b8s4k's shape,
-and its gradient), and the full-width mamba2-130m train step (2 layers).
+and its gradient), deepseek-7b-l2.b8s4k's train step with the residuals
+its remat keeps, and the full-width mamba2-130m train step (2 layers).
 
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached.  Nothing runs, so these tests say nothing about
@@ -25,6 +26,7 @@ from repro.kernels.ssd import ssd_intra_chunk  # noqa: E402
 from repro.launch import hlo_analysis  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.launch.train import build_trainer  # noqa: E402
+from repro.models import model as model_lib  # noqa: E402
 
 pytestmark = [pytest.mark.jax]
 
@@ -115,6 +117,26 @@ def test_ssd_intra_chunk_compiles_at_mamba2_130m_widths(one_chip):
     _compile_kernel(lambda *a: ssd_intra_chunk(*a, interpret=False),
                     one_chip, ((H, nc, Q, P), bf), ((H, nc, Q), f32),
                     ((H,), f32), ((1, nc, Q, N), bf), ((1, nc, Q, N), bf))
+
+
+def test_deepseek_step_keeps_core_and_mlp_on_one_chip(topo, monkeypatch,
+                                                      device_memory):
+    """deepseek-7b-l2.b8s4k's step as ``build_trainer`` builds it for a
+    v5e: the budget keeps the kernel's output and the MLP products (not
+    q, k, v), so the flash forward runs once a layer, not twice."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    device_memory(16_900_000_000)             # a v5e's limit (PERF.md §4)
+    cfg = dataclasses.replace(configs.get("deepseek-7b"), n_layers=2,
+                              vocab_size=12800)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    before = model_lib.REMAT.copy()
+    trainer = build_trainer(cfg, RunConfig(sync_mode="barrier", remat=True),
+                            mesh, batch=B, seq=S, steps=5000, lr=4.2e-4)
+    compiled = trainer.step.lower(trainer.state_shapes,
+                                  trainer.batch_shapes).compile()
+    assert model_lib.REMAT - before == {"core,mlp": 3_430_940_672}
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 3
 
 
 @pytest.mark.parametrize("sync_mode", ["barrier", "bucketed"])

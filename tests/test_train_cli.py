@@ -73,3 +73,17 @@ def test_done_line_counts_the_attention_paths(
           "--batch", "2", "--ckpt-dir", str(tmp_path / "ckpt")])
     done = capsys.readouterr().out.splitlines()[-1]
     assert re.search(r", attention \(xla_flash \d+\), loss ", done), done
+
+
+def test_done_line_names_the_tiers_remat_keeps(
+        tmp_path, monkeypatch, capsys, restore_cache_config, device_memory):
+    """``model.REMAT`` on the ``done:`` line, where the device reports its
+    memory: on the CPU's attention path the smoke llama names only its
+    MLP products."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    device_memory(10 ** 12)
+    main(["--arch", "deepseek-7b", "--smoke", "--steps", "1", "--seq", "256",
+          "--batch", "2", "--sync-mode", "barrier",
+          "--ckpt-dir", str(tmp_path / "ckpt")])
+    done = capsys.readouterr().out.splitlines()[-1]
+    assert re.search(r", remat: mlp 0\.\d\d GB, loss ", done), done
